@@ -58,6 +58,11 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else \
         {"atomics", "batch", "pool", "paper", "kernels", "serving"}
 
+    if only & {"kernels", "serving"}:
+        # the JAX families compile: keep those programs across runs
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+
     print("name,us_per_call,derived")
     t0 = time.time()
     families: dict = {}

@@ -41,13 +41,15 @@ def main():
                          "decoders; 'oneshot' is the stall-prone baseline; "
                          "'packed' executes chunked's grants as one "
                          "multi-segment chunk per step")
-    ap.add_argument("--backend", default="xla",
+    ap.add_argument("--backend", default=None,
                     choices=["xla", "pallas", "pallas_interpret"],
-                    help="kernel backend for the engine's attention ops: "
-                         "one flag flips decode (split-K paged attention) "
-                         "and packed prefill onto the Pallas kernels "
-                         "(Mosaic on TPU; interpret elsewhere — correct "
-                         "but slow off-TPU)")
+                    help="kernel backend for the engine's attention ops "
+                         "(default: the platform's — the Pallas kernels "
+                         "on a TPU, xla elsewhere).  'pallas' runs decode "
+                         "(split-K paged attention) and packed prefill "
+                         "through Mosaic and needs a TPU; "
+                         "'pallas_interpret' runs the same kernels in "
+                         "interpret mode (correct but slow)")
     ap.add_argument("--chunk-tokens", type=int, default=16,
                     help="per-step prefill token budget (page multiple)")
     ap.add_argument("--long-prompts", type=int, default=2,
@@ -133,7 +135,7 @@ def main():
     print(f"scheme={args.smr} shards={args.shards} "
           f"admission={args.admission} eviction={args.eviction} "
           f"scheduler={args.scheduler}/{args.chunk_tokens}tok "
-          f"backend={args.backend} "
+          f"backend={config.backend} "
           f"requests={res.requests} generated={res.tokens} tokens "
           f"in {res.duration_s:.2f}s ({res.tok_per_s:.1f} tok/s, "
           f"prefix hits={res.prefix_hits}, "

@@ -2,15 +2,20 @@
 serving engine.
 
 ``backend``:
-  * "xla"      — pure-jnp path (ref.py / blockwise-jnp): the CPU default.
-  * "pallas"   — the Pallas kernels (Mosaic on TPU; interpret=True on CPU —
-                 correct but slow, used by tests).
+  * "xla"              — pure-jnp path (ref.py / blockwise-jnp).
+  * "pallas"           — the Pallas kernels compiled by Mosaic; TPU only.
+  * "pallas_interpret" — the same kernels in Pallas interpret mode (correct
+                         but slow; what the CPU tests run).
+  * None               — the process default, which follows the platform:
+                         "pallas" on a TPU, "xla" anywhere else.
 
 The model zoo calls these wrappers so a single config flag flips the whole
 stack onto the TPU kernels.
 
-Dispatch honesty: when a call EXPLICITLY requests ``backend="pallas"`` but
-the kernel cannot take the shapes (block divisibility), the wrapper raises
+Dispatch honesty: ``backend="pallas"`` off a TPU raises — interpret mode
+is reached only by naming it, so a run that lost its chip cannot pass for
+a kernel run.  When a call EXPLICITLY requests a pallas backend but the
+kernel cannot take the shapes (block divisibility), the wrapper raises
 instead of silently dropping to the jnp reference — a silently changed
 execution path is how "the TPU run was slow" bugs hide.  When the pallas
 path is only the *session default* (``set_default_backend``), the fallback
@@ -31,25 +36,48 @@ from .packed_prefill import packed_prefill_attention as _packed_pallas
 from .paged_attention import paged_attention as _paged_pallas
 from .ssd_scan import ssd_scan as _ssd_pallas
 
-_DEFAULT_BACKEND = "xla"
+BACKENDS = ("xla", "pallas", "pallas_interpret")
+_DEFAULT_BACKEND: Optional[str] = None      # None → platform_backend()
 _FALLBACKS_WARNED: set = set()
 
 
+def platform_backend() -> str:
+    """The backend the platform calls for: the Mosaic kernels on a TPU,
+    the jnp path elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
 def default_backend() -> str:
-    return _DEFAULT_BACKEND
+    return _DEFAULT_BACKEND or platform_backend()
 
 
-def set_default_backend(name: str) -> None:
+def set_default_backend(name: Optional[str]) -> None:
+    """Pin the process default (None → follow the platform again)."""
     global _DEFAULT_BACKEND
-    assert name in ("xla", "pallas", "pallas_interpret")
+    if name is not None:
+        resolve_backend(name)
     _DEFAULT_BACKEND = name
 
 
+def resolve_backend(backend: Optional[str]) -> str:
+    """Validate a backend name (None → the process default).  Raises on an
+    unknown name, and on ``"pallas"`` when JAX's platform is not a TPU."""
+    b = backend or default_backend()
+    if b not in BACKENDS:
+        raise ValueError(f"unknown backend {b!r}; choose from {BACKENDS}")
+    platform = jax.default_backend()
+    if b == "pallas" and platform != "tpu":
+        raise RuntimeError(
+            f"backend='pallas' compiles the kernels with Mosaic, which "
+            f"needs a TPU, but JAX's platform is {platform!r}; ask for "
+            f"'pallas_interpret' to run them in interpret mode")
+    return b
+
+
 def _resolve(backend: Optional[str]):
-    b = backend or _DEFAULT_BACKEND
-    interpret = b == "pallas_interpret" or (
-        b == "pallas" and jax.default_backend() != "tpu")
-    return ("pallas" if b.startswith("pallas") else "xla"), interpret
+    b = resolve_backend(backend)
+    return ("pallas" if b.startswith("pallas") else "xla"), \
+        b == "pallas_interpret"
 
 
 def _refuse_fallback(op: str, explicit: bool, reason: str) -> None:

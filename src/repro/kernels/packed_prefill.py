@@ -9,13 +9,16 @@ position (which includes the segment's page-resident prefix: cache hits and
 earlier chunks) — and padding lanes (segment id -1) produce exactly zero
 output.
 
-Grid (Hkv, S, n_pages): for kv head ``hi``, segment ``si``, page ``pi``,
-the block-table entry ``page_rows[si, pi]`` selects the physical page
-(scalar-prefetched, no gather materialization) and ALL C chunk lanes score
-against it under the segment-indicator mask; fp32 online-softmax
-accumulators for every (lane, group-head) persist in VMEM scratch across
-the sequential (segment, page) walk.  Pages past a segment's context
-(``seg_ctx``) and segments with no lanes are skipped whole.
+Grid (S, n_pages): for segment ``si``, page ``pi``, the block-table entry
+``page_rows[si, pi]`` selects the physical page (scalar-prefetched, no
+gather materialization), loaded whole — all Hkv heads, block
+``(1, page, Hkv, D)``, the only page block whose last two dimensions
+Mosaic accepts without a head-major cache (DESIGN.md §13).  For each kv
+head in a static loop, ALL C chunk lanes score against it under the
+segment-indicator mask; fp32 online-softmax accumulators for every (kv
+head, lane, group-head) persist in VMEM scratch across the sequential
+(segment, page) walk.  Pages past a segment's context (``seg_ctx``) and
+segments with no lanes are skipped whole.
 
 The pure-jnp oracle is :func:`repro.kernels.ref.packed_prefill_attention_ref`;
 :mod:`repro.kernels.ops` dispatches between the two.
@@ -37,8 +40,8 @@ NEG_INF = -1e30
 def _packed_kernel(page_rows, seg_ctx, q_ref, k_ref, v_ref, seg_ref, pos_ref,
                    o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
                    n_segs: int, n_pages: int, scale: float):
-    si = pl.program_id(1)
-    pi = pl.program_id(2)
+    si = pl.program_id(0)
+    pi = pl.program_id(1)
 
     @pl.when(jnp.logical_and(si == 0, pi == 0))
     def _init():
@@ -52,41 +55,46 @@ def _packed_kernel(page_rows, seg_ctx, q_ref, k_ref, v_ref, seg_ref, pos_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[:, 0].astype(jnp.float32) * scale        # (C, G, D)
-        c, g, d = q.shape
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        s = jax.lax.dot_general(
-            q.reshape(c * g, d), k,
-            (((1,), (1,)), ((), ()))).reshape(c, g, -1)    # (C, G, page)
-        # sequence-indicator mask: lane l sees key position kp of page pi
-        # iff the lane belongs to THIS segment and kp is causally visible
-        # at the lane's absolute position (prefix pages included)
-        kp = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         seg = seg_ref[...]                                 # (C, 1) int32
         pos = pos_ref[...]                                 # (C, 1) int32
-        allowed = jnp.logical_and(seg[..., None] == si, kp <= pos[..., None])
-        s = jnp.where(allowed, s, NEG_INF)
-        m_prev = m_scr[...]                                # (C, G)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        # lanes of OTHER segments see an all-masked score row here; pin
-        # their running max to 0 before exponentiating so exp(s - m) is a
-        # clean 0, not exp(-inf - -inf) = 1
-        m_safe = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
-        p = jnp.exp(s - m_safe[..., None])
-        corr = jnp.exp(m_prev - m_safe)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        acc_scr[...] = acc_scr[...] * corr[..., None] + jax.lax.dot_general(
-            p.reshape(c * g, -1), v,
-            (((1,), (0,)), ((), ()))).reshape(c, g, d)
-        m_scr[...] = m_new
+        for h in range(k_ref.shape[2]):                    # static kv heads
+            q = q_ref[:, h].astype(jnp.float32) * scale    # (C, G, D)
+            c, g, d = q.shape
+            k = k_ref[0, :, h, :].astype(jnp.float32)      # (page, D)
+            s = jax.lax.dot_general(
+                q.reshape(c * g, d), k,
+                (((1,), (1,)), ((), ()))).reshape(c, g, -1)  # (C, G, page)
+            # sequence-indicator mask: lane l sees key position kp of page
+            # pi iff the lane belongs to THIS segment and kp is causally
+            # visible at the lane's absolute position (prefix pages
+            # included)
+            kp = pi * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2)
+            allowed = jnp.logical_and(seg[..., None] == si,
+                                      kp <= pos[..., None])
+            s = jnp.where(allowed, s, NEG_INF)
+            m_prev = m_scr[h]                              # (C, G)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            # lanes of OTHER segments see an all-masked score row here; pin
+            # their running max to 0 before exponentiating so exp(s - m) is
+            # a clean 0, not exp(-inf - -inf) = 1
+            m_safe = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
+            p = jnp.exp(s - m_safe[..., None])
+            corr = jnp.exp(m_prev - m_safe)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1)
+            v = v_ref[0, :, h, :].astype(jnp.float32)      # (page, D)
+            acc_scr[h] = acc_scr[h] * corr[..., None] + jax.lax.dot_general(
+                p.reshape(c * g, -1), v,
+                (((1,), (0,)), ((), ()))).reshape(c, g, d)
+            m_scr[h] = m_new
 
     @pl.when(jnp.logical_and(si == n_segs - 1, pi == n_pages - 1))
     def _finalize():
         # untouched lanes (padding: segment -1 matches no si) still hold
         # (acc=0, l=0): the epsilon divide pins their output to exactly 0
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[:, 0] = (acc_scr[...] / denom[..., None]).astype(o_ref.dtype)
+        for h in range(k_ref.shape[2]):
+            denom = jnp.maximum(l_scr[h], 1e-30)
+            o_ref[:, h] = (acc_scr[h] / denom[..., None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -114,27 +122,25 @@ def packed_prefill_attention(q, k_pages, v_pages, page_rows, seg_ids,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, n_segs, n_pages),
+        grid=(n_segs, n_pages),
         in_specs=[
-            pl.BlockSpec((c, 1, group, d),
-                         lambda hi, si, pi, rows, ctx: (0, hi, 0, 0)),
+            pl.BlockSpec((c, hkv, group, d), lambda si, pi, rows, ctx:
+                         (0, 0, 0, 0)),
             # the physical page for (segment si, logical page pi) comes from
             # the SMR-managed per-segment block table (scalar-prefetched)
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda hi, si, pi, rows, ctx:
-                         (rows[si, pi], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda hi, si, pi, rows, ctx:
-                         (rows[si, pi], 0, hi, 0)),
-            pl.BlockSpec((c, 1), lambda hi, si, pi, rows, ctx: (0, 0)),
-            pl.BlockSpec((c, 1), lambda hi, si, pi, rows, ctx: (0, 0)),
+            pl.BlockSpec((1, page_size, hkv, d), lambda si, pi, rows, ctx:
+                         (rows[si, pi], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, hkv, d), lambda si, pi, rows, ctx:
+                         (rows[si, pi], 0, 0, 0)),
+            pl.BlockSpec((c, 1), lambda si, pi, rows, ctx: (0, 0)),
+            pl.BlockSpec((c, 1), lambda si, pi, rows, ctx: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((c, 1, group, d),
-                               lambda hi, si, pi, rows, ctx: (0, hi, 0, 0)),
+        out_specs=pl.BlockSpec((c, hkv, group, d), lambda si, pi, rows, ctx:
+                               (0, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((c, group), jnp.float32),
-            pltpu.VMEM((c, group), jnp.float32),
-            pltpu.VMEM((c, group, d), jnp.float32),
+            pltpu.VMEM((hkv, c, group), jnp.float32),
+            pltpu.VMEM((hkv, c, group), jnp.float32),
+            pltpu.VMEM((hkv, c, group, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_packed_kernel, page_size=page_size,
@@ -143,8 +149,8 @@ def packed_prefill_attention(q, k_pages, v_pages, page_rows, seg_ids,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, hkv, group, d), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(page_rows, seg_ctx, qt, k_pages, v_pages, seg2, pos2)
     return out.reshape(c, h, d)

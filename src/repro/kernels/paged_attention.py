@@ -21,12 +21,17 @@ Two device-level properties the serving engine relies on (DESIGN.md §13):
   the page dimension (``dimension_semantics`` marks the split dim parallel
   for Mosaic's core mapping) instead of serializing the innermost grid.
 
-Tiling: grid (B, Hkv, num_splits, pages_per_split).  Page indirection goes
+Tiling: grid (B, num_splits, pages_per_split).  Page indirection goes
 through ``PrefetchScalarGridSpec``: the block-table entry selects which
 physical page is DMA'd into VMEM for each grid step (no gather
-materialization).  All G = H/Hkv query heads of a kv head are processed
-together as a (G, D) tile; fp32 online-softmax accumulators persist in VMEM
-scratch across the (innermost, sequential) page dimension of one split.
+materialization).  One grid step loads a WHOLE page — all Hkv heads,
+block ``(1, page, Hkv, D)`` — because Mosaic only accepts a block whose
+last two dimensions are (8, 128)-divisible or equal to the array's, and
+(Hkv, D) is the array's own; the kernel then walks the kv heads in a
+static loop, scoring each head's G = H/Hkv query heads as one (G, D) tile
+against that head's (page, D) keys (DESIGN.md §13).  fp32 online-softmax
+accumulators persist in VMEM scratch across the (innermost, sequential)
+page dimension of one split.
 """
 
 from __future__ import annotations
@@ -54,8 +59,8 @@ def _paged_kernel(block_tables, context_lens, occupancy, q_ref, k_ref, v_ref,
                   page_size: int, pages_per_split: int, n_pages: int,
                   scale: float):
     b = pl.program_id(0)
-    sp = pl.program_id(2)
-    pi = pl.program_id(3)
+    sp = pl.program_id(1)
+    pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
@@ -74,29 +79,30 @@ def _paged_kernel(block_tables, context_lens, occupancy, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, page)
-        pos = page_idx * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ctx, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (page, D)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + \
-            jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())))
-        m_scr[...] = m_new
+        for h in range(k_ref.shape[2]):                    # static kv heads
+            q = q_ref[0, h].astype(jnp.float32) * scale    # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)      # (page, D)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+            pos = page_idx * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(pos < ctx, s, NEG_INF)           # (G, page)
+            m_prev = m_scr[h]                              # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            v = v_ref[0, :, h, :].astype(jnp.float32)      # (page, D)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())))
+            m_scr[h] = m_new
 
     @pl.when(pi == pages_per_split - 1)
     def _finalize():
         # per-split partials: UNNORMALIZED accumulator + its own (m, l);
         # the cross-split combine rescales by exp(m - m_max) and divides
-        m_ref[0, 0, 0] = m_scr[...]
-        l_ref[0, 0, 0] = l_scr[...]
-        o_ref[0, 0, 0] = acc_scr[...]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
+        o_ref[0, 0] = acc_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("num_splits", "interpret"))
@@ -127,35 +133,36 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     # (B, Hkv, G, D) query tile layout
     qt = q.reshape(b, hkv, group, d)
 
-    def _page(bi, hi, sp, pi, bt, cl, oc):
+    def _page(bi, sp, pi, bt, cl, oc):
         # the physical page for logical page sp*pps+pi comes from the
         # SMR-managed block table (scalar-prefetched); ceil-division pad
         # slots clamp to the last entry and are masked dead in-kernel
         idx = jnp.minimum(sp * pages_per_split + pi, n_pages - 1)
-        return (bt[bi, idx], 0, hi, 0)
+        return (bt[bi, idx], 0, 0, 0)
+
+    def _row(bi, sp, pi, bt, cl, oc):
+        return (bi, 0, 0, 0)
+
+    def _split(bi, sp, pi, bt, cl, oc):
+        return (sp, bi, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, num_splits, pages_per_split),
+        grid=(b, num_splits, pages_per_split),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda bi, hi, sp, pi, bt, cl, oc: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d), _page),
-            pl.BlockSpec((1, page_size, 1, d), _page),
+            pl.BlockSpec((1, hkv, group, d), _row),
+            pl.BlockSpec((1, page_size, hkv, d), _page),
+            pl.BlockSpec((1, page_size, hkv, d), _page),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, group, d),
-                         lambda bi, hi, sp, pi, bt, cl, oc:
-                         (sp, bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, 1, group),
-                         lambda bi, hi, sp, pi, bt, cl, oc: (sp, bi, hi, 0)),
-            pl.BlockSpec((1, 1, 1, group),
-                         lambda bi, hi, sp, pi, bt, cl, oc: (sp, bi, hi, 0)),
+            pl.BlockSpec((1, 1, hkv, group, d), _split),
+            pl.BlockSpec((1, 1, hkv, group, 1), _split),
+            pl.BlockSpec((1, 1, hkv, group, 1), _split),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, d), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, page_size=page_size,
@@ -166,12 +173,11 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((num_splits, b, hkv, group, d), jnp.float32),
-            jax.ShapeDtypeStruct((num_splits, b, hkv, group), jnp.float32),
-            jax.ShapeDtypeStruct((num_splits, b, hkv, group), jnp.float32),
+            jax.ShapeDtypeStruct((num_splits, b, hkv, group, 1), jnp.float32),
+            jax.ShapeDtypeStruct((num_splits, b, hkv, group, 1), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, context_lens, occ, qt, k_pages, v_pages)
 
@@ -179,10 +185,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens, *,
     # split's partial to the global max, sum mass and accumulators, divide.
     # Dead splits (m = -inf from padding/occupancy) contribute weight 0; a
     # fully dead row (all splits dead) divides 0 by the epsilon → exactly 0.
-    m_max = jnp.max(m, axis=0)                              # (B,Hkv,G)
+    m_max = jnp.max(m, axis=0)                              # (B,Hkv,G,1)
     w = jnp.where(m > NEG_INF * 0.5,
                   jnp.exp(m - jnp.maximum(m_max, NEG_INF * 0.5)[None]), 0.0)
-    l_tot = jnp.sum(l * w, axis=0)                          # (B,Hkv,G)
-    out = jnp.sum(acc * w[..., None], axis=0) / \
-        jnp.maximum(l_tot, 1e-30)[..., None]                # (B,Hkv,G,D)
+    l_tot = jnp.sum(l * w, axis=0)                          # (B,Hkv,G,1)
+    out = jnp.sum(acc * w, axis=0) / \
+        jnp.maximum(l_tot, 1e-30)                           # (B,Hkv,G,D)
     return out.astype(q.dtype).reshape(b, h, d)

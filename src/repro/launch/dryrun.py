@@ -177,10 +177,6 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        # older jax returns a one-element list of dicts; newer returns the
-        # dict directly — normalize so the lookups below work on both
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_info = {

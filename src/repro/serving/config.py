@@ -15,6 +15,7 @@ from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
 from .. import api
+from ..kernels.ops import resolve_backend
 
 __all__ = ["ServingConfig", "PriorityClass", "parse_priority_class"]
 
@@ -130,12 +131,13 @@ class ServingConfig:
 
     # -- device backend ----------------------------------------------------
     # kernel backend for the engine's attention ops (kernels/ops.py):
-    # "xla" (pure-jnp reference path, the CPU default), "pallas" (the
-    # Mosaic kernels — flash-decoding split-K paged attention and the
-    # packed-prefill kernel — on TPU; interpret mode on CPU), or
-    # "pallas_interpret" (force interpret mode: bit-accurate but slow,
-    # used by tests).  One flag flips the whole engine onto the TPU path.
-    backend: str = "xla"
+    # None (resolved at construction from the platform: "pallas" on a TPU,
+    # "xla" elsewhere), "xla" (pure-jnp path), "pallas" (the Mosaic
+    # kernels — flash-decoding split-K paged attention and the
+    # packed-prefill kernel; TPU only, raises elsewhere) or
+    # "pallas_interpret" (the same kernels in interpret mode: bit-accurate
+    # but slow, used by tests).
+    backend: Optional[str] = None
 
     # -- speculative decoding (DESIGN.md §17) ------------------------------
     # draft depth per round: 0 disables speculation (every token comes
@@ -272,10 +274,8 @@ class ServingConfig:
         if self.spec_draft_layers < 0:
             raise ValueError(f"spec_draft_layers must be >= 0 (0 = half "
                              f"the target), got {self.spec_draft_layers}")
-        if self.backend not in ("xla", "pallas", "pallas_interpret"):
-            raise ValueError(
-                f"unknown backend {self.backend!r}; choose from "
-                f"('xla', 'pallas', 'pallas_interpret')")
+        # raises on an unknown name, and on "pallas" off a TPU
+        object.__setattr__(self, "backend", resolve_backend(self.backend))
         if self.watchdog not in ("migrate", "observe", "off"):
             raise ValueError(f"unknown watchdog mode {self.watchdog!r}; "
                              f"choose from ('migrate', 'observe', 'off')")

@@ -480,9 +480,11 @@ class _ShardEngine:
         return req
 
     # ------------------------------------------------------------- device fns
-    def _layer_params(self, i):
-        return jax.tree_util.tree_map(lambda p: p[i],
-                                      self.params["blocks"])
+    @staticmethod
+    def _layer_params(params, i):
+        # slice the TRACED argument: closing over self.params would bake
+        # every block's weights into the program as constants
+        return jax.tree_util.tree_map(lambda p: p[i], params["blocks"])
 
     def _paged_prefill(self, params, k_pages, v_pages, tokens, page_ids,
                        start, n_valid, sampf, sampi):
@@ -530,7 +532,7 @@ class _ShardEngine:
         # causal triangle); pages past the prompt are never unmasked
         kmask = jnp.arange(s_max)[None, :] <= abs_pos[:, None]   # (C, S)
         for i in range(cfg.n_layers):
-            p = self._layer_params(i)
+            p = self._layer_params(params, i)
             h = rms_norm(x, p["ln1"])
             q, k, v = _qkv(p["attn"], cfg, h)
             q = apply_rope(q, angles)
@@ -612,7 +614,7 @@ class _ShardEngine:
         # never touch a page, whatever their (clamped) row aliases
         upd_page = jnp.where(valid, page_of, k_pages.shape[1])
         for i in range(cfg.n_layers):
-            p = self._layer_params(i)
+            p = self._layer_params(params, i)
             h = rms_norm(x, p["ln1"])
             q, k, v = _qkv(p["attn"], cfg, h)
             q = apply_rope(q, angles)
@@ -678,7 +680,7 @@ class _ShardEngine:
         allowed = (seg_ids[:, None] == key_seg[None, :]) & \
             (key_pos[None, :] <= positions[:, None])     # (L, P*pgsz)
         for i in range(cfg.n_layers):
-            p = self._layer_params(i)
+            p = self._layer_params(params, i)
             h = rms_norm(x, p["ln1"])
             q, k, v = _qkv(p["attn"], cfg, h)
             q = apply_rope(q, angles)
@@ -734,7 +736,7 @@ class _ShardEngine:
         page_idx = jnp.where(occ, page_idx, k_pages.shape[1])
         slot_idx = (ctx_lens - 1) % self.page_size
         for i in range(cfg.n_layers):
-            p = self._layer_params(i)
+            p = self._layer_params(params, i)
             h = rms_norm(x, p["ln1"])
             q, k, v = _qkv(p["attn"], cfg, h)
             q = apply_rope(q, angles)
@@ -910,7 +912,7 @@ class _ShardEngine:
         angles = rope_angles(positions[None, :], cfg.head_dim,
                              cfg.rope_theta)
         for i in range(cfg.n_layers):
-            p = self._layer_params(i)
+            p = self._layer_params(params, i)
             h = rms_norm(x, p["ln1"])
             q, k, v = _qkv(p["attn"], cfg, h)
             q = apply_rope(q, angles)
@@ -1656,6 +1658,36 @@ class _ShardEngine:
                 if n_b >= self.max_batch:
                     break
                 n_b = min(self.max_batch, n_b * 2)
+
+    def _idle_decode_args(self):
+        """Decode-step operands of an all-padding batch: no row is
+        occupied, so every K/V write drops and the tokens are discarded."""
+        b = self.max_batch
+        return (jnp.zeros((b, self.max_pages), jnp.int32),
+                jnp.ones((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+                jnp.zeros((b,), bool), jnp.zeros((b, 2), jnp.float32),
+                jnp.zeros((b, 2), jnp.int32))
+
+    def warm_decode(self) -> None:
+        """Pre-compile the batched decode step with an all-padding batch —
+        a pure jit-cache warm, safe on a live engine (step lock).  No-op
+        under speculative decoding, whose rounds replace the decode step
+        (:meth:`warm_spec`)."""
+        if self.spec_k:
+            return
+        with self._step_lock:
+            toks, _, self.k_pages, self.v_pages = self._decode(
+                self.params, self.k_pages, self.v_pages,
+                *self._idle_decode_args())
+            jax.block_until_ready(toks)
+
+    def decode_hlo(self) -> str:
+        """Optimized HLO of the compiled decode step — the program the
+        device runs, e.g. to check that the Pallas kernels are in it."""
+        with self._step_lock:
+            return self._decode.lower(
+                self.params, self.k_pages, self.v_pages,
+                *self._idle_decode_args()).compile().as_text()
 
     def warm_spec(self) -> None:
         """Pre-compile the speculative round's two dispatches

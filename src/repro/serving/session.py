@@ -334,13 +334,15 @@ class ServingSession:
         self.engine.start()
 
     def warm(self) -> None:
-        """Pre-compile the packed-prefill segment buckets, the
-        speculative-decoding propose/verify dispatches (when ``spec_k`` is
-        on), and (when the swap tier is on) the per-page device↔host
-        movers on every shard, so jit cost never lands on a live request's
-        latency.  Safe before or after :meth:`start`."""
+        """Pre-compile the packed-prefill segment buckets, the decode step
+        (or, when ``spec_k`` is on, the speculative propose/verify
+        dispatches that replace it), and (when the swap tier is on) the
+        per-page device↔host movers on every shard, so jit cost never
+        lands on a live request's latency.  Safe before or after
+        :meth:`start`."""
         for shard in self.engine.shards:
             shard.warm_packed()
+            shard.warm_decode()
             shard.warm_spec()
             shard.warm_swap()
 

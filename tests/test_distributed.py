@@ -16,6 +16,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(script: str, n_devices: int = 8, timeout: int = 560):
     env = dict(os.environ)
+    # the virtual devices are CPU devices: never let a child reach for an
+    # accelerator the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -32,6 +35,7 @@ def test_sharded_train_step_matches_single_device():
     stdout = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import build_model
         from repro.parallel.sharding import axis_rules, param_sharding, resolve
         from repro.train.optimizer import make_optimizer
@@ -53,7 +57,7 @@ def test_sharded_train_step_matches_single_device():
         # single-device reference
         loss_ref, params_ref = jax.jit(step)(params, opt_state, tokens)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with axis_rules(mesh):
             _, sp = model.abstract_params()
             p_sh = param_sharding(sp, mesh,
@@ -83,7 +87,7 @@ def test_dryrun_cell_small_mesh():
         # monkeypatch the production mesh to the available 8 devices
         import repro.launch.mesh as mesh_mod
         mesh_mod.make_production_mesh = \
-            lambda multi_pod=False: jax.make_mesh(
+            lambda multi_pod=False: mesh_mod.make_mesh(
                 (2, 2, 2) if multi_pod else (2, 4),
                 ("pod", "data", "model") if multi_pod else ("data", "model"))
         dr.make_production_mesh = mesh_mod.make_production_mesh

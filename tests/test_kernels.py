@@ -279,6 +279,27 @@ def test_ops_default_pallas_warns_once_on_fallback():
     np.testing.assert_allclose(np.asarray(first), np.asarray(again))
 
 
+def test_ops_pallas_refuses_non_tpu():
+    """backend='pallas' means Mosaic on a TPU: on the CPU it raises rather
+    than drop to interpret mode, so a run that lost its chip cannot pass
+    for a kernel run.  The process default follows the platform."""
+    assert jax.default_backend() == "cpu"
+    assert ops.default_backend() == "xla"
+    b, h, hkv, d, nphys, page, npg = 2, 4, 2, 16, 4, 4, 2
+    q = jnp.ones((b, h, d), jnp.float32)
+    kp = jnp.ones((nphys, page, hkv, d), jnp.float32)
+    bt = jnp.zeros((b, npg), jnp.int32)
+    cl = jnp.ones((b,), jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ops.paged_attention(q, kp, kp, bt, cl, backend="pallas")
+    seg = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ops.packed_prefill_attention(jnp.ones((4, h, d)), kp, kp, bt[:1],
+                                     seg, seg, cl[:1], backend="pallas")
+    with pytest.raises(ValueError, match="unknown backend"):
+        ops.paged_attention(q, kp, kp, bt, cl, backend="mosaic")
+
+
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
 def test_packed_prefill_ops_backends_agree(backend):
     """ops.packed_prefill_attention: both backends match the oracle on a

@@ -369,3 +369,14 @@ def test_packed_stats_surface():
 
     with pytest.raises(ValueError, match="unknown backend"):
         ServingConfig(backend="cuda")
+
+
+def test_backend_follows_the_platform():
+    """The default backend is resolved at construction from the platform
+    (the CPU here: "xla"); naming "pallas" off a TPU raises instead of
+    falling back to interpret mode."""
+    assert ServingConfig().backend == "xla"
+    assert ServingConfig(backend="pallas_interpret").backend == \
+        "pallas_interpret"
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ServingConfig(backend="pallas")

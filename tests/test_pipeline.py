@@ -39,6 +39,7 @@ def test_gpipe_matches_sequential():
         print("GPIPE_OK")
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # virtual CPU devices, never the chip
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.join(REPO, "src")
