@@ -28,6 +28,11 @@ from .compile_cache import enable_compile_cache
 DEFAULT_SERVING = dict(num_pages=2048, page_size=16, max_batch=8,
                        max_seq_len=512, prefill_chunk_tokens=256,
                        scheduler="packed")
+# the traffic a launcher run sends, and chip_smoke.py with it: REQUESTS
+# prompts of PROMPT_LEN tokens (inclusive range), NEW_TOKENS new tokens each
+REQUESTS = 8
+PROMPT_LEN = (64, 256)
+NEW_TOKENS = 32
 
 
 def build(arch: str, seed: int, *, reduced: bool = False):
@@ -60,21 +65,18 @@ def main(argv=None):
                     help="seeds the random weights and the prompts")
     ap.add_argument("--smr", default="IBR")
     ap.add_argument("--shards", type=int, default=1)
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--prompt-len", type=int, nargs=2, default=(64, 256),
-                    metavar=("MIN", "MAX"))
-    ap.add_argument("--max-new", type=int, default=32)
+    ap.add_argument("--requests", type=int, default=REQUESTS)
     args = ap.parse_args(argv)
 
     enable_compile_cache()
     model, params = build(args.arch, args.seed, reduced=args.reduced)
     config = ServingConfig(smr=args.smr, num_shards=args.shards,
                            **DEFAULT_SERVING)
-    prompts = random_prompts(args.seed, args.requests, args.prompt_len,
+    prompts = random_prompts(args.seed, args.requests, PROMPT_LEN,
                              model.cfg.vocab_size)
     with serve(model, params, config) as session:
         session.warm()
-        handles = session.submit_many(prompts, max_new_tokens=args.max_new)
+        handles = session.submit_many(prompts, max_new_tokens=NEW_TOKENS)
         for h in handles:
             h.result(timeout=600)
         totals = session.stats()["totals"]

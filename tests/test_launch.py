@@ -42,6 +42,27 @@ def test_random_prompts_are_seeded_and_in_range():
     assert all(4 <= len(p) <= 9 and 0 < min(p) and max(p) < 50 for p in a)
 
 
+def test_chip_smoke_counts_kernel_error_in_bf16_ulps():
+    """A one-ulp rounding difference reads 1 at any magnitude >= 1 and
+    less below it, where the ulp of 1 is the unit."""
+    import importlib.util
+    from pathlib import Path
+
+    import jax.numpy as jnp
+
+    path = Path(compile_cache.__file__).resolve().parents[3] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+
+    want = jnp.asarray([3.0, 0.25, 1.0, -2.0], jnp.bfloat16)
+    got = jnp.asarray([3.0 + 2 ** -6, 0.25 + 2 ** -9, 1.0 + 2 ** -7, -2.0],
+                      jnp.bfloat16)
+    assert chip_smoke.errors(jnp, got, want) == (2 ** -6, 1.0)
+    assert chip_smoke.errors(jnp, got.at[3].add(-2 ** -5), want) == (
+        2 ** -5, 2.0)
+
+
 def test_warm_compiles_the_decode_step():
     """session.warm() compiles the decode step, so serving compiles it no
     more; decode_hlo() hands back that program's optimized HLO."""
@@ -68,8 +89,7 @@ def test_serve_launcher_reduced(capsys, monkeypatch, restore_cache_dir,
     """The launcher's whole path at the reduced size: build from a seed,
     warm, serve every request, print the session totals."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    serve.main(["--reduced", "--requests", "3", "--prompt-len", "8", "24",
-                "--max-new", "4"])
+    serve.main(["--reduced", "--requests", "3"])
     out = capsys.readouterr().out
     assert "tinyllama-1.1b-reduced" in out and "backend=xla" in out
     assert "'completed': 3" in out and "'failed': 0" in out
