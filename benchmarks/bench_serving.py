@@ -237,7 +237,7 @@ def bench_serving(quick=True):
         # counters as deltas over the timed window (warmup compiles can
         # legitimately trigger migrations of the probe requests)
         delta = {k: st[k] - st0.get(k, 0)
-                 for k in ("migrations", "failed_requests")}
+                 for k in ("migrations", "failed")}
         return dt, toks, terminal, delta
 
     dt_h, toks_h, term_h, _ = _stall_run(None, "migrate")
@@ -257,7 +257,7 @@ def bench_serving(quick=True):
         yield (f"serving/{name},{dt / max(toks, 1) * 1e6:.1f},"
                f"tok_s={tok_s:.1f};vs_healthy={tok_s / tok_s_h:.2f}x;"
                f"migrations={st['migrations']:.0f};"
-               f"failed={st['failed_requests']:.0f};terminal={int(term)}")
+               f"failed={st['failed']:.0f};terminal={int(term)}")
 
     # oversubscription family (DESIGN.md §15): a ~10x-oversubscribed mix —
     # long low-priority decoders holding every page, then a burst of short

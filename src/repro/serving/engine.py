@@ -52,6 +52,7 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.smr.base import SmrScheme
 from ..kernels import ops
@@ -63,6 +64,7 @@ from ..runtime.prefix_cache import PrefixCache
 from ..runtime.swap import SwapArena, SwapArenaFullError, SwapChecksumError
 from .config import ServingConfig
 from .faults import build_fault_line
+from .phases import PhaseClock
 from .policies import as_admission_policy, as_scheduler_policy
 from .sampling import SamplingPolicy, as_sampling_policy
 
@@ -112,6 +114,9 @@ class Request:
     # so TTFT and inter-token latencies are measurable without polling
     t_submit: float = 0.0
     out_times: List[float] = field(default_factory=list)
+    # dispatch stamp of the first chunk holding this request's prompt:
+    # splits TTFT into waiting (before it) and prefill service (after it)
+    t_first_chunk: Optional[float] = None
     # set on every generated token and on completion (stream wakeups)
     _progress: threading.Event = field(default_factory=threading.Event)
     # filled at submit time (client thread): prefix-cache hit
@@ -307,6 +312,12 @@ class _ShardEngine:
         self.prefill_tokens_wasted = 0
         self.packed_chunks = 0
         self.packed_segments = 0
+        # host seconds per step-loop phase (serving/phases.py), and the
+        # TTFT split summed over first tokens (stats())
+        self.phase = PhaseClock()
+        self.prefill_wait_s = 0.0
+        self.prefill_s = 0.0
+        self.first_tokens = 0
         # fault tolerance (DESIGN.md §14): the shard's scheduled faults,
         # its loop heartbeat, and the recovery counters stats() exposes
         self.fault_line = build_fault_line(config.faults, shard_id)
@@ -1034,8 +1045,9 @@ class _ShardEngine:
             if req.cancelled.is_set():
                 self._fail_out(req, "cancelled")
                 continue
-            if not self._admit_one(req):
-                return
+            with TraceAnnotation("engine.admit.one", req_id=req.req_id):
+                if not self._admit_one(req):
+                    return
 
     def _admit_one(self, req: Request) -> bool:
         """Reserve this request's pages and enqueue it for prefill;
@@ -1218,6 +1230,12 @@ class _ShardEngine:
         seq.tokens.append(tok)
         req = seq.req
         now = time.perf_counter()
+        if not req.out_times:
+            # the TTFT split: waiting (admission, other prompts' chunks)
+            # until its first chunk's dispatch, then its own prefill
+            self.prefill_wait_s += req.t_first_chunk - req.t_submit
+            self.prefill_s += now - req.t_first_chunk
+            self.first_tokens += 1
         if req._gap_pending and req.out_times:
             # the incoming interval spans a preemption park or a migration
             # stall: mark it as a SERVICE GAP — excluded from itl() and
@@ -1254,30 +1272,55 @@ class _ShardEngine:
         immediately) and move the sequence to decoding."""
         req = seq.req
         sp = req.sampling
-        sampf = jnp.asarray([sp.temperature, sp.top_p], jnp.float32)
-        sampi = jnp.asarray([sp.top_k, sp.seed], jnp.int32)
+        with self.phase("dispatch"):
+            sampf = jnp.asarray([sp.temperature, sp.top_p], jnp.float32)
+            sampi = jnp.asarray([sp.top_k, sp.seed], jnp.int32)
         n_prompt = len(req.prompt)
         chunk = self.config.prefill_chunk_tokens
         end = min(seq.filled + grant, n_prompt)
         tok = lp = None
         while seq.filled < end:
-            n_valid = min(chunk, end - seq.filled)
-            buf = np.zeros((1, chunk), np.int32)
-            buf[0, :n_valid] = req.prompt[seq.filled:seq.filled + n_valid]
-            self._fault_dispatch()
-            tok, lp, self.k_pages, self.v_pages = self._prefill(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(buf), jnp.asarray(seq.page_row),
-                jnp.int32(seq.filled), jnp.int32(n_valid),
-                sampf, sampi)
+            with self.phase("plan"):
+                n_valid = min(chunk, end - seq.filled)
+                buf = np.zeros((1, chunk), np.int32)
+                buf[0, :n_valid] = \
+                    req.prompt[seq.filled:seq.filled + n_valid]
+            with self.phase("dispatch"):
+                self._stamp_dispatch([seq])
+                self._fault_dispatch()
+                tok, lp, self.k_pages, self.v_pages = self._prefill(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.asarray(buf), jnp.asarray(seq.page_row),
+                    jnp.int32(seq.filled), jnp.int32(n_valid),
+                    sampf, sampi)
             seq.filled += n_valid
             self.prefill_chunks += 1
             self.prefill_tokens_wasted += chunk - n_valid
         if seq.filled == n_prompt:
             # final chunk: its last-position logits ARE the first token
-            self._finish_prefill(seq, int(tok), float(lp))
+            tok, lp = self._fetch(tok, lp)
+            with self.phase("emit"):
+                self._finish_prefill(seq, int(tok), float(lp))
         # intermediate chunks never sync with the device (tok is dropped
         # untouched), so chunking adds no host round-trips
+
+    @staticmethod
+    def _stamp_dispatch(seqs) -> None:
+        """Stamp the dispatch of each request's first prompt chunk."""
+        now = time.perf_counter()
+        for seq in seqs:
+            if seq.req.t_first_chunk is None:
+                seq.req.t_first_chunk = now
+
+    def _fetch(self, *outs):
+        """A step's outputs as host arrays, in the ``sync`` phase:
+        ``engine.sync.wait`` while the device still computes them,
+        ``engine.sync.copy`` while they are read back."""
+        with self.phase("sync"):
+            with TraceAnnotation("engine.sync.wait"):
+                jax.block_until_ready(outs)
+            with TraceAnnotation("engine.sync.copy"):
+                return tuple(np.asarray(o) for o in outs)
 
     def _finish_prefill(self, seq: _Seq, tok: int, lp: float = 0.0) -> None:
         """A sequence's prompt is fully in pages and its first token is in
@@ -1352,128 +1395,138 @@ class _ShardEngine:
         rider_toks = None
         first = True
         while queue:
-            toks = np.zeros((1, lanes_max), np.int32)
-            segs = np.full((lanes_max,), -1, np.int32)
-            poss = np.zeros((lanes_max,), np.int32)
-            # per-lane scatter targets (flat path); padding lanes point
-            # out of bounds and are dropped on device
-            upd = np.full((lanes_max,), self.config.num_pages, np.int32)
-            slot = np.zeros((lanes_max,), np.int32)
-            rows = np.zeros((n_segs, self.max_pages), np.int32)
-            ctxs = np.zeros((n_segs,), np.int32)
-            emit = np.full((n_segs,), lanes_max, np.int32)  # not finishing
-            # per-segment sampling operands + the absolute position each
-            # emitting segment samples AT (the counter-PRNG coordinate)
-            sampf = np.zeros((n_segs, 2), np.float32)
-            sampi = np.zeros((n_segs, 2), np.int32)
-            spos = np.zeros((n_segs,), np.int32)
-            seg_pages = []       # (page_row, n_live_pages) per segment
-            members = []
-            lane = 0
-            budget = len(riders) if first else 0
-            while queue and lane < chunk and len(members) + budget < n_segs:
-                seq, grant = queue.pop(0)
-                take = min(grant, chunk - lane)
-                si = len(members)
-                pos = np.arange(seq.filled, seq.filled + take)
-                toks[0, lane:lane + take] = \
-                    seq.req.prompt[seq.filled:seq.filled + take]
-                segs[lane:lane + take] = si
-                poss[lane:lane + take] = pos
-                upd[lane:lane + take] = seq.page_row[pos // pgsz]
-                slot[lane:lane + take] = pos % pgsz
-                rows[si] = seq.page_row
-                ctxs[si] = seq.filled + take
-                sp = seq.req.sampling
-                sampf[si] = (sp.temperature, sp.top_p)
-                sampi[si] = (sp.top_k, sp.seed)
-                spos[si] = seq.filled + take
-                seg_pages.append((seq.page_row,
-                                  -(-(seq.filled + take) // pgsz)))
-                if seq.filled + take == len(seq.req.prompt):
-                    emit[si] = lane + take - 1
-                members.append((seq, take))
-                lane += take
-                if take < grant:
-                    # chunk overflow: the remainder LEADS the next chunk.
-                    # A mid-chunk split point need not be page-aligned —
-                    # alignment only matters at STEP end (prefix-cache
-                    # resume), and the full grant lands within this plan.
-                    queue.insert(0, (seq, grant - take))
-            n_riders = 0
-            if first:
-                for seq in riders:
-                    si = len(members) + n_riders
-                    ctx = len(seq.tokens)
-                    toks[0, lane] = seq.tokens[-1]
-                    segs[lane] = si
-                    poss[lane] = ctx - 1
-                    upd[lane] = seq.page_row[(ctx - 1) // pgsz]
-                    slot[lane] = (ctx - 1) % pgsz
+            with self.phase("plan"):
+                toks = np.zeros((1, lanes_max), np.int32)
+                segs = np.full((lanes_max,), -1, np.int32)
+                poss = np.zeros((lanes_max,), np.int32)
+                # per-lane scatter targets (flat path); padding lanes point
+                # out of bounds and are dropped on device
+                upd = np.full((lanes_max,), self.config.num_pages, np.int32)
+                slot = np.zeros((lanes_max,), np.int32)
+                rows = np.zeros((n_segs, self.max_pages), np.int32)
+                ctxs = np.zeros((n_segs,), np.int32)
+                # lanes_max: the segment does not finish in this chunk
+                emit = np.full((n_segs,), lanes_max, np.int32)
+                # per-segment sampling operands + the absolute position each
+                # emitting segment samples AT (the counter-PRNG coordinate)
+                sampf = np.zeros((n_segs, 2), np.float32)
+                sampi = np.zeros((n_segs, 2), np.int32)
+                spos = np.zeros((n_segs,), np.int32)
+                seg_pages = []       # (page_row, n_live_pages) per segment
+                members = []
+                lane = 0
+                budget = len(riders) if first else 0
+                while queue and lane < chunk and \
+                        len(members) + budget < n_segs:
+                    seq, grant = queue.pop(0)
+                    take = min(grant, chunk - lane)
+                    si = len(members)
+                    pos = np.arange(seq.filled, seq.filled + take)
+                    toks[0, lane:lane + take] = \
+                        seq.req.prompt[seq.filled:seq.filled + take]
+                    segs[lane:lane + take] = si
+                    poss[lane:lane + take] = pos
+                    upd[lane:lane + take] = seq.page_row[pos // pgsz]
+                    slot[lane:lane + take] = pos % pgsz
                     rows[si] = seq.page_row
-                    ctxs[si] = ctx
+                    ctxs[si] = seq.filled + take
                     sp = seq.req.sampling
                     sampf[si] = (sp.temperature, sp.top_p)
                     sampi[si] = (sp.top_k, sp.seed)
-                    spos[si] = ctx
-                    seg_pages.append((seq.page_row, -(-ctx // pgsz)))
-                    emit[si] = lane
-                    n_riders += 1
-                    lane += 1
-            self.prefill_chunks += 1
-            self.packed_chunks += 1
-            self.packed_segments += len(members)
-            self.prefill_tokens_wasted += chunk - (lane - n_riders)
-            total = len(members) + n_riders
-            self._fault_dispatch()
-            if flat_path:
-                # ragged key layout: segments' LIVE pages laid end to end,
-                # the page total bucketed to a power of two (≥ 8) — the
-                # call pays for the aggregate context actually attended,
-                # never the (segments × max_pages) rectangle
-                n_pages = sum(n for _, n in seg_pages)
-                p_b = max(8, 1 << max(0, n_pages - 1).bit_length())
-                pages = np.zeros((3, p_b), np.int32)
-                pages[1] = -1                      # padding owns no lane
-                off = 0
-                for si, (row, n) in enumerate(seg_pages):
-                    pages[0, off:off + n] = row[:n]
-                    pages[1, off:off + n] = si
-                    pages[2, off:off + n] = np.arange(n) * pgsz
-                    off += n
-                lanes = np.stack([toks[0], segs, poss, upd, slot])
-                out_toks, out_lps, self.k_pages, self.v_pages = \
-                    self._packed_flat(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.asarray(lanes), jnp.asarray(pages),
-                        jnp.asarray(emit), jnp.asarray(sampf),
-                        jnp.asarray(sampi), jnp.asarray(spos))
-            else:
-                # power-of-2 segment bucket: pay for the segments actually
-                # present, not max_batch (seg ids are compact, so a prefix
-                # slice of the per-segment operands is sufficient)
-                n_b = min(n_segs, 1 << max(0, total - 1).bit_length())
-                out_toks, out_lps, self.k_pages, self.v_pages = \
-                    self._prefill_packed(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.asarray(toks), jnp.asarray(segs),
-                        jnp.asarray(poss), jnp.asarray(rows[:n_b]),
-                        jnp.asarray(ctxs[:n_b]), jnp.asarray(emit[:n_b]),
-                        jnp.asarray(sampf[:n_b]), jnp.asarray(sampi[:n_b]),
-                        jnp.asarray(spos[:n_b]))
+                    spos[si] = seq.filled + take
+                    seg_pages.append((seq.page_row,
+                                      -(-(seq.filled + take) // pgsz)))
+                    if seq.filled + take == len(seq.req.prompt):
+                        emit[si] = lane + take - 1
+                    members.append((seq, take))
+                    lane += take
+                    if take < grant:
+                        # chunk overflow: the remainder LEADS the next
+                        # chunk.  A mid-chunk split point need not be
+                        # page-aligned — alignment only matters at STEP end
+                        # (prefix-cache resume), and the full grant lands
+                        # within this plan.
+                        queue.insert(0, (seq, grant - take))
+                n_riders = 0
+                if first:
+                    for seq in riders:
+                        si = len(members) + n_riders
+                        ctx = len(seq.tokens)
+                        toks[0, lane] = seq.tokens[-1]
+                        segs[lane] = si
+                        poss[lane] = ctx - 1
+                        upd[lane] = seq.page_row[(ctx - 1) // pgsz]
+                        slot[lane] = (ctx - 1) % pgsz
+                        rows[si] = seq.page_row
+                        ctxs[si] = ctx
+                        sp = seq.req.sampling
+                        sampf[si] = (sp.temperature, sp.top_p)
+                        sampi[si] = (sp.top_k, sp.seed)
+                        spos[si] = ctx
+                        seg_pages.append((seq.page_row, -(-ctx // pgsz)))
+                        emit[si] = lane
+                        n_riders += 1
+                        lane += 1
+                self.prefill_chunks += 1
+                self.packed_chunks += 1
+                self.packed_segments += len(members)
+                self.prefill_tokens_wasted += chunk - (lane - n_riders)
+                total = len(members) + n_riders
+                if flat_path:
+                    # ragged key layout: segments' LIVE pages laid end to
+                    # end, the page total bucketed to a power of two (≥ 8)
+                    # — the call pays for the aggregate context actually
+                    # attended, never the (segments × max_pages) rectangle
+                    n_pages = sum(n for _, n in seg_pages)
+                    p_b = max(8, 1 << max(0, n_pages - 1).bit_length())
+                    pages = np.zeros((3, p_b), np.int32)
+                    pages[1] = -1                  # padding owns no lane
+                    off = 0
+                    for si, (row, n) in enumerate(seg_pages):
+                        pages[0, off:off + n] = row[:n]
+                        pages[1, off:off + n] = si
+                        pages[2, off:off + n] = np.arange(n) * pgsz
+                        off += n
+                    lanes = np.stack([toks[0], segs, poss, upd, slot])
+            with self.phase("dispatch"):
+                self._stamp_dispatch(seq for seq, _ in members)
+                self._fault_dispatch()
+                if flat_path:
+                    out_toks, out_lps, self.k_pages, self.v_pages = \
+                        self._packed_flat(
+                            self.params, self.k_pages, self.v_pages,
+                            jnp.asarray(lanes), jnp.asarray(pages),
+                            jnp.asarray(emit), jnp.asarray(sampf),
+                            jnp.asarray(sampi), jnp.asarray(spos))
+                else:
+                    # power-of-2 segment bucket: pay for the segments
+                    # actually present, not max_batch (seg ids are compact,
+                    # so a prefix slice of the per-segment operands is
+                    # sufficient)
+                    n_b = min(n_segs, 1 << max(0, total - 1).bit_length())
+                    out_toks, out_lps, self.k_pages, self.v_pages = \
+                        self._prefill_packed(
+                            self.params, self.k_pages, self.v_pages,
+                            jnp.asarray(toks), jnp.asarray(segs),
+                            jnp.asarray(poss), jnp.asarray(rows[:n_b]),
+                            jnp.asarray(ctxs[:n_b]),
+                            jnp.asarray(emit[:n_b]),
+                            jnp.asarray(sampf[:n_b]),
+                            jnp.asarray(sampi[:n_b]),
+                            jnp.asarray(spos[:n_b]))
             finishing = any(emit[si] < lanes_max
                             for si in range(len(members)))
             # only a chunk that emits tokens (some prompt completed, or
             # decode riders aboard) syncs with the device
             out_np = lps_np = None
             if finishing or n_riders:
-                out_np = np.asarray(out_toks)
-                lps_np = np.asarray(out_lps)
-            for si, (seq, take) in enumerate(members):
-                seq.filled += take
-                if emit[si] < lanes_max:
-                    self._finish_prefill(seq, int(out_np[si]),
-                                         float(lps_np[si]))
+                out_np, lps_np = self._fetch(out_toks, out_lps)
+            with self.phase("emit"):
+                for si, (seq, take) in enumerate(members):
+                    seq.filled += take
+                    if emit[si] < lanes_max:
+                        self._finish_prefill(seq, int(out_np[si]),
+                                             float(lps_np[si]))
             if n_riders:
                 rider_toks = (
                     out_np[len(members):len(members) + n_riders],
@@ -1501,60 +1554,63 @@ class _ShardEngine:
         b = self.max_batch
         kd = self.spec_k
         s_max = self.max_pages * self.page_size
-        tok_buf = np.zeros((b, s_max), np.int32)
-        ctx = np.ones((b,), np.int32)
-        nd = np.zeros((b,), np.int32)
-        occ = np.zeros((b,), bool)
-        rows = np.zeros((b, self.max_pages), np.int32)
-        x_last = np.zeros((b,), np.int32)
-        sampf = np.zeros((b, 2), np.float32)
-        sampi = np.zeros((b, 2), np.int32)
-        for i, seq in enumerate(batch):
-            t = len(seq.tokens)
-            tok_buf[i, :t] = seq.tokens
-            ctx[i] = t
-            remaining = seq.req.max_new_tokens - seq.new_tokens
-            capacity = len(seq.pages) * self.page_size
-            nd[i] = max(0, min(kd, remaining - 1, capacity - t))
-            occ[i] = True
-            rows[i] = seq.page_row
-            x_last[i] = seq.tokens[-1]
-            sp = seq.req.sampling
-            sampf[i] = (sp.temperature, sp.top_p)
-            sampi[i] = (sp.top_k, sp.seed)
-        self._fault_dispatch()
-        d_toks, q_dists = self._draft_propose(
-            self.draft_params, jnp.asarray(tok_buf), jnp.asarray(ctx),
-            jnp.asarray(sampf), jnp.asarray(sampi))
-        self._fault_dispatch()
-        # d_toks/q_dists stay on device between the two dispatches — the
-        # only host sync in the round is reading the verdict below
-        toks_o, n_emit, lps, self.k_pages, self.v_pages = self._spec_verify(
-            self.params, self.k_pages, self.v_pages, jnp.asarray(x_last),
-            d_toks, jnp.asarray(ctx), jnp.asarray(nd), jnp.asarray(occ),
-            jnp.asarray(rows), jnp.asarray(sampf), jnp.asarray(sampi),
-            q_dists)
-        toks_np = np.asarray(toks_o)
-        n_np = np.asarray(n_emit)
-        lps_np = np.asarray(lps)
-        done = []
-        for i, seq in enumerate(batch):
-            req = seq.req
-            self.n_draft_proposed += int(nd[i])
-            self.n_draft_accepted += int(n_np[i]) - 1
-            for j in range(int(n_np[i])):
+        with self.phase("plan"):
+            tok_buf = np.zeros((b, s_max), np.int32)
+            ctx = np.ones((b,), np.int32)
+            nd = np.zeros((b,), np.int32)
+            occ = np.zeros((b,), bool)
+            rows = np.zeros((b, self.max_pages), np.int32)
+            x_last = np.zeros((b,), np.int32)
+            sampf = np.zeros((b, 2), np.float32)
+            sampi = np.zeros((b, 2), np.int32)
+            for i, seq in enumerate(batch):
+                t = len(seq.tokens)
+                tok_buf[i, :t] = seq.tokens
+                ctx[i] = t
+                remaining = seq.req.max_new_tokens - seq.new_tokens
+                capacity = len(seq.pages) * self.page_size
+                nd[i] = max(0, min(kd, remaining - 1, capacity - t))
+                occ[i] = True
+                rows[i] = seq.page_row
+                x_last[i] = seq.tokens[-1]
+                sp = seq.req.sampling
+                sampf[i] = (sp.temperature, sp.top_p)
+                sampi[i] = (sp.top_k, sp.seed)
+        with self.phase("dispatch"):
+            self._fault_dispatch()
+            d_toks, q_dists = self._draft_propose(
+                self.draft_params, jnp.asarray(tok_buf), jnp.asarray(ctx),
+                jnp.asarray(sampf), jnp.asarray(sampi))
+            self._fault_dispatch()
+            # d_toks/q_dists stay on device between the two dispatches —
+            # the only host sync in the round is reading the verdict below
+            toks_o, n_emit, lps, self.k_pages, self.v_pages = \
+                self._spec_verify(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.asarray(x_last), d_toks, jnp.asarray(ctx),
+                    jnp.asarray(nd), jnp.asarray(occ), jnp.asarray(rows),
+                    jnp.asarray(sampf), jnp.asarray(sampi), q_dists)
+        toks_np, n_np, lps_np = self._fetch(toks_o, n_emit, lps)
+        with self.phase("emit"):
+            done = []
+            for i, seq in enumerate(batch):
+                req = seq.req
+                self.n_draft_proposed += int(nd[i])
+                self.n_draft_accepted += int(n_np[i]) - 1
+                for j in range(int(n_np[i])):
+                    if seq.new_tokens >= req.max_new_tokens \
+                            or req.cancelled.is_set() or req._stop_hit:
+                        break
+                    self._emit(seq, int(toks_np[i, j]),
+                               float(lps_np[i, j]))
+                    seq.new_tokens += 1
                 if seq.new_tokens >= req.max_new_tokens \
                         or req.cancelled.is_set() or req._stop_hit:
-                    break
-                self._emit(seq, int(toks_np[i, j]), float(lps_np[i, j]))
-                seq.new_tokens += 1
-            if seq.new_tokens >= req.max_new_tokens \
-                    or req.cancelled.is_set() or req._stop_hit:
-                done.append(seq)
-        for seq in done:
-            self._active.remove(seq)
-            self._finish(seq, "cancelled" if seq.req.cancelled.is_set()
-                         else "done")
+                    done.append(seq)
+            for seq in done:
+                self._active.remove(seq)
+                self._finish(seq, "cancelled" if seq.req.cancelled.is_set()
+                             else "done")
 
     def _release_seq(self, seq: _Seq) -> None:
         for pg in seq.pages[seq.owned_from:]:
@@ -1717,30 +1773,40 @@ class _ShardEngine:
             jax.block_until_ready(out)
 
     def step(self) -> bool:
-        """One engine iteration; returns False when idle."""
+        """One engine iteration; returns False when idle.  An iteration
+        that starts with work waiting or in flight is one ``engine.step``
+        span, numbered by ``steps``."""
         with self._step_lock:
-            return self._step_locked()
+            if not (self._active or self._prefilling
+                    or self.waiting_count()):
+                return self._step_locked()
+            with TraceAnnotation("engine.step", step=self.steps):
+                return self._step_locked()
 
     def _step_locked(self) -> bool:
-        self._sweep_deadlines()
-        self._admit()
+        with self.phase("admit"):
+            self._sweep_deadlines()
+            self._admit()
         if not self._active and not self._prefilling:
             return False
         # drop cancelled prefilling sequences before spending budget on
         # them — their reserved pages (and hit pins) go straight back
-        for seq in [s for s in self._prefilling
-                    if s.req.cancelled.is_set()]:
-            self._prefilling.remove(seq)
-            self._finish(seq, "cancelled")
+        reaped = [s for s in self._prefilling if s.req.cancelled.is_set()]
+        if reaped:
+            with self.phase("emit"):
+                for seq in reaped:
+                    self._prefilling.remove(seq)
+                    self._finish(seq, "cancelled")
         # prefill phase: at most prefill_chunk_tokens of prompt ingestion,
         # divided by the scheduler policy — the ITL bound for everyone
         # already decoding is one chunk, never one prompt
         decoded = None
         batch_seqs = []
         if self._prefilling:
-            plan = self.scheduler.plan(
-                list(self._prefilling), self.config.prefill_chunk_tokens,
-                self.page_size)
+            with self.phase("plan"):
+                plan = self.scheduler.plan(
+                    list(self._prefilling),
+                    self.config.prefill_chunk_tokens, self.page_size)
             if getattr(self.scheduler, "packs", False):
                 # packed path: the WHOLE plan rides one fixed-shape chunk,
                 # and the step's decode batch rides it too (fused step) —
@@ -1767,40 +1833,46 @@ class _ShardEngine:
                 self._spec_round()
             else:
                 batch_seqs = list(self._active)
-                bt = np.zeros((self.max_batch, self.max_pages), np.int32)
-                ctx = np.ones((self.max_batch,), np.int32)
-                toks = np.zeros((self.max_batch,), np.int32)
-                occ = np.zeros((self.max_batch,), bool)
-                sampf = np.zeros((self.max_batch, 2), np.float32)
-                sampi = np.zeros((self.max_batch, 2), np.int32)
-                for i, seq in enumerate(batch_seqs):
-                    bt[i, :] = seq.page_row
-                    ctx[i] = len(seq.tokens)
-                    toks[i] = seq.tokens[-1]
-                    occ[i] = True
-                    sp = seq.req.sampling
-                    sampf[i] = (sp.temperature, sp.top_p)
-                    sampi[i] = (sp.top_k, sp.seed)
-                self._fault_dispatch()
-                toks_d, lps_d, self.k_pages, self.v_pages = self._decode(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.asarray(bt), jnp.asarray(ctx), jnp.asarray(toks),
-                    jnp.asarray(occ), jnp.asarray(sampf),
-                    jnp.asarray(sampi))
-                decoded = (np.asarray(toks_d), np.asarray(lps_d))
+                b = self.max_batch
+                with self.phase("plan"):
+                    bt = np.zeros((b, self.max_pages), np.int32)
+                    ctx = np.ones((b,), np.int32)
+                    toks = np.zeros((b,), np.int32)
+                    occ = np.zeros((b,), bool)
+                    sampf = np.zeros((b, 2), np.float32)
+                    sampi = np.zeros((b, 2), np.int32)
+                    for i, seq in enumerate(batch_seqs):
+                        bt[i, :] = seq.page_row
+                        ctx[i] = len(seq.tokens)
+                        toks[i] = seq.tokens[-1]
+                        occ[i] = True
+                        sp = seq.req.sampling
+                        sampf[i] = (sp.temperature, sp.top_p)
+                        sampi[i] = (sp.top_k, sp.seed)
+                with self.phase("dispatch"):
+                    self._fault_dispatch()
+                    toks_d, lps_d, self.k_pages, self.v_pages = \
+                        self._decode(
+                            self.params, self.k_pages, self.v_pages,
+                            jnp.asarray(bt), jnp.asarray(ctx),
+                            jnp.asarray(toks), jnp.asarray(occ),
+                            jnp.asarray(sampf), jnp.asarray(sampi))
+                decoded = self._fetch(toks_d, lps_d)
         if decoded is not None:
             next_toks, next_lps = decoded
-            done = []
-            for i, seq in enumerate(batch_seqs):
-                self._emit(seq, int(next_toks[i]), float(next_lps[i]))
-                seq.new_tokens += 1
-                if seq.new_tokens >= seq.req.max_new_tokens \
-                        or seq.req.cancelled.is_set() or seq.req._stop_hit:
-                    done.append(seq)
-            for seq in done:
-                self._active.remove(seq)
-                self._finish(seq, "cancelled" if seq.req.cancelled.is_set()
-                             else "done")
+            with self.phase("emit"):
+                done = []
+                for i, seq in enumerate(batch_seqs):
+                    self._emit(seq, int(next_toks[i]), float(next_lps[i]))
+                    seq.new_tokens += 1
+                    if seq.new_tokens >= seq.req.max_new_tokens \
+                            or seq.req.cancelled.is_set() \
+                            or seq.req._stop_hit:
+                        done.append(seq)
+                for seq in done:
+                    self._active.remove(seq)
+                    self._finish(seq, "cancelled"
+                                 if seq.req.cancelled.is_set() else "done")
         self.steps += 1
         if self.degraded:
             # the watchdog flagged us stalled but the loop is advancing:
@@ -1837,7 +1909,8 @@ class _ShardEngine:
                 if self.fault_line is not None:
                     self.fault_line.before_step(self)
                 if not self.step():
-                    time.sleep(sleep_s)
+                    with self.phase("idle"):
+                        time.sleep(sleep_s)
         except BaseException as exc:  # noqa: BLE001 — the crash guard
             self._crash(exc)
         finally:
@@ -1936,6 +2009,10 @@ class _ShardEngine:
             "packed_segments_per_chunk": (
                 self.packed_segments / self.packed_chunks
                 if self.packed_chunks else 0.0),
+            **self.phase.stats(),
+            "prefill_wait_s": self.prefill_wait_s,
+            "prefill_s": self.prefill_s,
+            "first_tokens": self.first_tokens,
             "beat": self.beat,
             "degraded": self.degraded,
             "crashed": self.crashed,

@@ -33,6 +33,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Union
 from ..runtime.prefix_cache import _prefix_key
 from .config import ServingConfig
 from .engine import Request, _ShardEngine
+from .phases import PHASES
 
 __all__ = ["PrefixRouter", "ShardedEngine", "RequestHandle",
            "ServingSession", "serve"]
@@ -465,7 +466,6 @@ class ServingSession:
             "migrations_in": sum(s["migrated_in"] for s in shards),
             "heartbeat_misses": sum(s["heartbeat_misses"] for s in shards),
             "degraded_steps": sum(s["degraded_steps"] for s in shards),
-            "failed_requests": sum(s["failed"] for s in shards),
             "crashed_shards": sum(1 for s in shards if s["crashed"]),
             "degraded_shards": sum(1 for s in shards if s["degraded"]),
             # swap tier + priority-class SLOs (DESIGN.md §15)
@@ -485,6 +485,11 @@ class ServingSession:
                               if s["swap"] is not None),
             "swap_bytes_used": sum(s["swap"]["bytes_used"] for s in shards
                                    if s["swap"] is not None),
+            # host seconds per step-loop phase and the TTFT split
+            # (serving/phases.py)
+            **{k: sum(s[k] for s in shards)
+               for k in [f"host_s_{p}" for p in PHASES]
+               + ["prefill_wait_s", "prefill_s", "first_tokens"]},
         }
         # chunk-weighted mean across shards (NOT a mean of per-shard means)
         totals["packed_segments_per_chunk"] = (

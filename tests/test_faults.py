@@ -207,7 +207,7 @@ def test_stall_migrates_live_sequences_token_exact(small_model):
             "migrated continuation diverged from the unfaulted decode"
     totals = session.stats()["totals"]
     assert totals["migrations"] >= 1, "stall never forced a migration"
-    assert totals["failed_requests"] == 0
+    assert totals["failed"] == 0
     assert totals["heartbeat_misses"] >= 1
     # the stalled shard recovers once its loop beats again
     deadline = time.perf_counter() + 10.0

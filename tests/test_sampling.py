@@ -528,7 +528,7 @@ def test_sampled_migration_token_exact(small_model):
     totals = session.stats()["totals"]
     session.close()
     assert totals["migrations"] >= 1, "stall never forced a migration"
-    assert totals["failed_requests"] == 0
+    assert totals["failed"] == 0
     want = _uninterrupted(model, params, longs, 20, pol)
     for out, w in zip(outs, want):
         assert out == w, \

@@ -410,7 +410,7 @@ def test_pool_exhaust_chaos_composes_with_swap(small_model):
     assert hi.status == "done", (hi.status, hi.req.error)
     assert hi.result() == _ref(model, params, hi_p, 40)
     assert totals["preemptions"] >= 1
-    assert totals["failed_requests"] == 0
+    assert totals["failed"] == 0
     assert low.status == "done", (low.status, low.req.error)
     assert low.result() == _ref(model, params, low_p, 48)
 
