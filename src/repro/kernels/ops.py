@@ -187,12 +187,33 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, position, *,
     ``(seed, position, stream)`` (see :mod:`repro.kernels.ref`), which is
     what makes swap/migration replay reproduce tokens without RNG state.
 
-    There is no Pallas variant: the math is a handful of (B,V) jnp ops that
-    fuse into the enclosing jit (the engine's decode/prefill device fns stay
-    one dispatch), so both backends share the reference formulation."""
+    The reference sorts the whole vocabulary twice per row, takes a cumsum
+    and a scatter over it and draws Gumbel noise for every entry: at a
+    151 936-token vocabulary on a TPU, more device time than the model's
+    layers.  Inside its vmap a greedy row cannot skip that (the greedy test
+    is a per-row value, so XLA computes both sides and selects), so the gate
+    sits outside: a batch with no sampled row takes a ``lax.cond`` branch
+    that is just ``argmax`` with zero logprobs — what the reference returns
+    for greedy rows, bit for bit.  Padding rows carry zero operands and
+    count as greedy.
+
+    There is no Pallas variant: both branches are jnp ops inside the
+    enclosing jit (the engine's step programs stay one dispatch), so both
+    backends share the reference formulation."""
     del backend  # single formulation; kept for dispatch-signature parity
-    return ref.sample_tokens_ref(logits, temperature, top_k, top_p, seed,
-                                 position, stream=stream)
+
+    def argmax_only(logits, *_):
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                jnp.zeros(logits.shape[:1], jnp.float32))
+
+    def full(logits, temperature, top_k, top_p, seed, position):
+        return ref.sample_tokens_ref(logits, temperature, top_k, top_p, seed,
+                                     position, stream=stream)
+
+    # the same greedy test as sample_token_ref, over the whole batch
+    all_greedy = jnp.all(jnp.asarray(temperature, jnp.float32) <= 0.0)
+    return jax.lax.cond(all_greedy, argmax_only, full, logits, temperature,
+                        top_k, top_p, seed, position)
 
 
 def spec_verify_rows(p_dist, q_dist, draft_toks, n_draft, seed, base_pos, *,

@@ -287,6 +287,10 @@ class _ShardEngine:
                                        v.at[:, pid].set(vp)),
             donate_argnums=(0, 1))
         self.steps = 0
+        # dispatches of a sampling step program, and those whose batch held
+        # no sampled row (ops.sample_tokens' argmax-only branch)
+        self.sampler_calls = 0
+        self.sampler_argmax_calls = 0
         self.n_completed = 0
         self.n_cancelled = 0
         self.n_failed = 0
@@ -1273,7 +1277,8 @@ class _ShardEngine:
         req = seq.req
         sp = req.sampling
         with self.phase("dispatch"):
-            sampf = jnp.asarray([sp.temperature, sp.top_p], jnp.float32)
+            sampf_h = np.asarray([sp.temperature, sp.top_p], np.float32)
+            sampf = jnp.asarray(sampf_h)
             sampi = jnp.asarray([sp.top_k, sp.seed], jnp.int32)
         n_prompt = len(req.prompt)
         chunk = self.config.prefill_chunk_tokens
@@ -1293,6 +1298,7 @@ class _ShardEngine:
                     jnp.asarray(buf), jnp.asarray(seq.page_row),
                     jnp.int32(seq.filled), jnp.int32(n_valid),
                     sampf, sampi)
+                self._count_sampler(sampf_h[:1])
             seq.filled += n_valid
             self.prefill_chunks += 1
             self.prefill_tokens_wasted += chunk - n_valid
@@ -1303,6 +1309,13 @@ class _ShardEngine:
                 self._finish_prefill(seq, int(tok), float(lp))
         # intermediate chunks never sync with the device (tok is dropped
         # untouched), so chunking adds no host round-trips
+
+    def _count_sampler(self, temperature: np.ndarray) -> None:
+        """Count one dispatch of a sampling step program from the host
+        copy of the row temperatures it sent: with none above zero the
+        program takes ``ops.sample_tokens``' argmax-only branch."""
+        self.sampler_calls += 1
+        self.sampler_argmax_calls += bool(np.all(temperature <= 0.0))
 
     @staticmethod
     def _stamp_dispatch(seqs) -> None:
@@ -1498,6 +1511,7 @@ class _ShardEngine:
                             jnp.asarray(lanes), jnp.asarray(pages),
                             jnp.asarray(emit), jnp.asarray(sampf),
                             jnp.asarray(sampi), jnp.asarray(spos))
+                    self._count_sampler(sampf[:, 0])
                 else:
                     # power-of-2 segment bucket: pay for the segments
                     # actually present, not max_batch (seg ids are compact,
@@ -1514,6 +1528,7 @@ class _ShardEngine:
                             jnp.asarray(sampf[:n_b]),
                             jnp.asarray(sampi[:n_b]),
                             jnp.asarray(spos[:n_b]))
+                    self._count_sampler(sampf[:n_b, 0])
             finishing = any(emit[si] < lanes_max
                             for si in range(len(members)))
             # only a chunk that emits tokens (some prompt completed, or
@@ -1857,6 +1872,7 @@ class _ShardEngine:
                             jnp.asarray(bt), jnp.asarray(ctx),
                             jnp.asarray(toks), jnp.asarray(occ),
                             jnp.asarray(sampf), jnp.asarray(sampi))
+                    self._count_sampler(sampf[:, 0])
                 decoded = self._fetch(toks_d, lps_d)
         if decoded is not None:
             next_toks, next_lps = decoded
@@ -1984,6 +2000,8 @@ class _ShardEngine:
             "prefix_cache": self.prefix_cache.stats(),
             "smr": self.smr.stats(),
             "steps": self.steps,
+            "sampler_calls": self.sampler_calls,
+            "sampler_argmax_calls": self.sampler_argmax_calls,
             "active": len(self._active),
             "prefilling": len(self._prefilling),
             "waiting": self.waiting_count(),

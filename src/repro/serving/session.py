@@ -438,6 +438,9 @@ class ServingSession:
         shards = self.engine.stats()
         totals: Dict[str, float] = {
             "steps": sum(s["steps"] for s in shards),
+            "sampler_calls": sum(s["sampler_calls"] for s in shards),
+            "sampler_argmax_calls": sum(s["sampler_argmax_calls"]
+                                        for s in shards),
             "active": sum(s["active"] for s in shards),
             "prefilling": sum(s["prefilling"] for s in shards),
             "waiting": sum(s["waiting"] for s in shards),

@@ -28,6 +28,7 @@ Layers, bottom up:
 import time
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -103,6 +104,79 @@ def test_sample_token_greedy_matches_argmax():
     tok, lp = kref.sample_token_ref(logits, 0.0, 0, 1.0, 7, 3)
     assert int(tok) == int(np.argmax(np.asarray(logits)))
     assert float(lp) == 0.0
+
+
+def _gate_operands(case):
+    """(B=16, V=512) logits and the per-row operands of one gate case."""
+    b, v = 16, 512
+    logits = jnp.asarray(np.random.RandomState(2).randn(b, v), jnp.bfloat16)
+    temp = np.zeros(b, np.float32)
+    top_k = np.zeros(b, np.int32)
+    top_p = np.zeros(b, np.float32)
+    seed = np.zeros(b, np.int32)
+    if case == "greedy":
+        top_p[:] = 1.0
+    elif case == "greedy_with_operands":
+        top_k[:], top_p[:], seed[:] = 20, 0.8, np.arange(b) + 11
+    elif case == "one_sampled":
+        top_p[:] = 1.0
+        temp[5], top_k[5], seed[5] = 0.8, 30, 42
+    elif case == "all_sampled":
+        temp[:] = 0.9
+        top_k[::2], top_p[::2] = 25, 1.0
+        top_p[1::2] = 0.7
+        seed[:] = np.arange(b) * 7 + 3
+    else:
+        assert case == "padding"
+    position = np.arange(b, dtype=np.int32) + 100
+    return logits, temp, top_k, top_p, seed, position
+
+
+@pytest.mark.parametrize("case", ["greedy", "greedy_with_operands",
+                                  "padding", "one_sampled", "all_sampled"])
+def test_sample_tokens_gate_matches_oracle_bit_for_bit(case):
+    """The batch-level argmax gate in ops.sample_tokens returns exactly
+    what the reference returns, tokens and logprobs, whichever branch the
+    batch takes."""
+    from repro.kernels import ops
+    args = [jnp.asarray(a) for a in _gate_operands(case)]
+    tok, lp = jax.jit(ops.sample_tokens)(*args)
+    want_tok, want_lp = kref.sample_tokens_ref(*args)
+    assert tok.dtype == want_tok.dtype and lp.dtype == want_lp.dtype
+    np.testing.assert_array_equal(np.asarray(tok), np.asarray(want_tok))
+    assert np.asarray(lp).tobytes() == np.asarray(want_lp).tobytes()
+    if case in ("one_sampled", "all_sampled"):
+        assert np.any(np.asarray(lp) != 0.0)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in ``jaxpr`` and its sub-jaxprs."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    names |= _primitives(sub)
+    return names
+
+
+def test_sample_tokens_argmax_branch_has_no_sort():
+    """A greedy batch must not pay for the full-vocabulary sort: the
+    sampler holds a cond (outside any vmap) one of whose branches has an
+    argmax and no sort, and the other the reference's sort."""
+    from repro.kernels import ops
+    args = [jnp.asarray(a) for a in _gate_operands("greedy")]
+    closed = jax.make_jaxpr(jax.jit(ops.sample_tokens))(*args)
+    (call,) = closed.jaxpr.eqns
+    conds = [e for e in call.params["jaxpr"].jaxpr.eqns
+             if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    branches = [_primitives(br.jaxpr) for br in conds[0].params["branches"]]
+    argmax_only = [b for b in branches if "sort" not in b]
+    assert len(argmax_only) == 1 and "argmax" in argmax_only[0]
+    assert any("sort" in b for b in branches)
 
 
 def test_spec_verify_identical_dists_accept_all():
@@ -336,6 +410,29 @@ def test_topk_topp_match_oracle(small_model, policy):
     prompt = list(rng.randint(1, 200, size=11))
     (out,) = _run(model, params, [prompt], 6, sampling=policy)
     assert out == _reference_sampled(model, params, prompt, 6, policy)
+
+
+@pytest.mark.parametrize("scheduler", ["packed", "chunked"])
+def test_sampler_counters_follow_the_batch(small_model, scheduler):
+    """Every sampling dispatch counts in ``sampler_calls``; those whose
+    batch holds no sampled row count in ``sampler_argmax_calls`` too."""
+    model, params = small_model
+    rng = np.random.RandomState(8)
+    prompts = [list(rng.randint(1, 200, size=n)) for n in (9, 14)]
+    conf = _config(scheduler=scheduler, prefill_chunk_tokens=8)
+    _, greedy = _run(model, params, prompts, 4, conf=conf, want_stats=True)
+    assert greedy["sampler_calls"] > 0
+    assert greedy["sampler_argmax_calls"] == greedy["sampler_calls"]
+    session = serving.serve(model, params, conf)
+    hs = [session.submit(prompts[0], max_new_tokens=4),
+          session.submit(prompts[1], max_new_tokens=4,
+                         sampling=TemperatureSampling(temperature=0.8,
+                                                      seed=5))]
+    for h in hs:
+        h.result(timeout=300)
+    mixed = session.stats()["totals"]
+    session.close()
+    assert 0 < mixed["sampler_argmax_calls"] < mixed["sampler_calls"]
 
 
 def test_logprobs_recorded(small_model):

@@ -139,11 +139,13 @@ def test_engine_spans_nest_in_the_profiler_trace(small_model, tmp_path):
 # ------------------------------------------------------------- the readers
 
 BEFORE = {"steps": 10, "first_tokens": 2, "prefill_wait_s": 1.0,
-          "prefill_s": 2.0, **{f"host_s_{p}": 1.0 for p in PHASES}}
+          "prefill_s": 2.0, "sampler_calls": 12, "sampler_argmax_calls": 9,
+          **{f"host_s_{p}": 1.0 for p in PHASES}}
 AFTER = {"steps": 60, "first_tokens": 6, "prefill_wait_s": 3.0,
          "prefill_s": 2.6, "host_s_idle": 2.5, "host_s_admit": 1.05,
          "host_s_plan": 1.1, "host_s_dispatch": 1.5, "host_s_sync": 5.0,
-         "host_s_emit": 1.2}
+         "host_s_emit": 1.2, "sampler_calls": 52,
+         "sampler_argmax_calls": 39}
 WINDOW_S = 10.0
 
 
@@ -155,7 +157,8 @@ WINDOW_S = 10.0
     ("host_ms_per_step.emit", 4.0, "steps"),
     ("engine_idle_share", 15.0, None),
     ("prefill_wait_ms.mean", 500.0, "first_tokens"),
-    ("prefill_ms.mean", 150.0, "first_tokens")])
+    ("prefill_ms.mean", 150.0, "first_tokens"),
+    ("sampler_argmax_share", 75.0, "sampler_calls")])
 def test_phase_readers(metric, value, denominator):
     read = harness.load_reader(metric)
     dims = harness.load_cell("qwen3-8b-l12.decode").dims

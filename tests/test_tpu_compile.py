@@ -5,7 +5,10 @@ rules; these tests ask the TPU compiler itself, for a described — not
 attached — v5e chip, at the TinyLlama-1.1B serving shapes: bf16, 32 query
 and 4 kv heads of 64, 2048 pages, 8 sequences of up to 512 tokens, packed
 chunks of 256 prefill lanes plus 8 decode riders.  Page size 16 is the
-served one; 8 is ``ServingConfig``'s default.
+served one; 8 is ``ServingConfig``'s default.  The sampler compiles at
+the benchmark's Qwen3 vocabulary (151 936) and 16 rows, and keeps its
+argmax-only branch a real conditional: were the compiler to flatten it
+into a select, a greedy batch would pay for the full-vocabulary sort.
 
 Everything that touches the TPU library happens inside the fixtures below,
 never at import: the library admits one process at a time.
@@ -16,11 +19,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.packed_prefill import packed_prefill_attention
 from repro.kernels.paged_attention import paged_attention
 
 H, HKV, D = 32, 4, 64
 POOL_PAGES, BATCH, MAX_SEQ, LANES = 2048, 8, 512, 256 + 8
+VOCAB, SAMPLE_ROWS = 151936, 16
 
 
 @pytest.fixture(scope="module")
@@ -75,3 +80,12 @@ def test_packed_prefill_compiles_for_v5e(one_chip, page):
         (pool, jnp.bfloat16), ((BATCH, n_pages), jnp.int32),
         ((LANES,), jnp.int32), ((LANES,), jnp.int32), ((BATCH,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_sampler_gate_compiles_for_v5e(one_chip):
+    rows = (SAMPLE_ROWS,)
+    hlo = _compile(
+        ops.sample_tokens, one_chip, ((SAMPLE_ROWS, VOCAB), jnp.bfloat16),
+        (rows, jnp.float32), (rows, jnp.int32), (rows, jnp.float32),
+        (rows, jnp.int32), (rows, jnp.int32))
+    assert " conditional(" in hlo
